@@ -33,6 +33,7 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 
 import jax  # noqa: E402
 
+from repro import obs  # noqa: E402
 from repro.kernels.interpret import INTERPRET_ENV, resolve_interpret  # noqa: E402
 from repro.launch import train  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
@@ -75,27 +76,6 @@ class Fail(Exception):
     """A check of the smoke test failed."""
 
 
-def _compile_clock():
-    """Seconds in XLA's backend compiles, and the counts of persistent
-    compile-cache hits and writes, accumulated from the call on."""
-    totals = {"compile_s": 0.0, "cache_hits": 0, "cache_writes": 0}
-
-    def on_duration(event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            totals["compile_s"] += duration
-
-    def on_event(event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            totals["cache_hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            # jax records this event as it writes an entry
-            totals["cache_writes"] += 1
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-    jax.monitoring.register_event_listener(on_event)
-    return totals
-
-
 def peak_bytes_in_use() -> str:
     """Peak device memory of this process so far (first chip)."""
     stats = jax.devices()[0].memory_stats()
@@ -116,10 +96,11 @@ def cache_contents(path: str) -> str:
             + ", ".join(f"{n[:40]} {b}" for n, b in top))
 
 
-def run_phase(name: str, extra: list, clock: dict) -> train.TrainRun:
+def run_phase(name: str, extra: list,
+              clock: obs.CompileCounter) -> train.TrainRun:
     """One training run through ``train.main``; prints its numbers."""
     print(f"== {name}: {' '.join(extra)}", flush=True)
-    before = dict(clock)
+    before = clock.snapshot()
     t0 = time.perf_counter()
     run = train.main(BASE_ARGS + extra)
     wall = time.perf_counter() - t0
@@ -136,9 +117,10 @@ def run_phase(name: str, extra: list, clock: dict) -> train.TrainRun:
     # The round program compiles before the first chunk
     # (--donation-report), so set-up covers init plus that compile.
     setup = wall - sum(s for _, s in run.chunk_seconds)
-    print(f"{name}: compile_s={clock['compile_s'] - before['compile_s']:.1f} "
-          f"cache_hits={clock['cache_hits'] - before['cache_hits']} "
-          f"cache_writes={clock['cache_writes'] - before['cache_writes']} "
+    after = clock.snapshot()
+    print(f"{name}: compile_s={after['compile_s'] - before['compile_s']:.1f} "
+          f"cache_hits={after['cache_hits'] - before['cache_hits']} "
+          f"cache_writes={after['cache_misses'] - before['cache_misses']} "
           f"setup_s={setup:.2f} "
           f"first_chunk_s={run.chunk_seconds[0][1]:.4f} "
           f"per_round_s={per_round:.4f} wall_s={wall:.1f} "
@@ -210,7 +192,7 @@ def main() -> int:
           f"depth cut {LAYERS} of {train.get_config(ARCH).n_layers} "
           f"layers; {cfg.n_params():,} parameters", flush=True)
 
-    clock = _compile_clock()
+    clock = obs.CompileCounter()
     compiled = ["--no-interpret"]
     if args.chips == 4:
         sharded = run_phase("pallas_sharded",

@@ -58,6 +58,7 @@ same trajectory from the same key.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Any, Callable, NamedTuple, Optional, Tuple
@@ -65,6 +66,7 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.adaptive import (AdaptiveConfig, ServerOptState,
                                  apply_slab_update, make_server_optimizer,
                                  slab_update_slabs)
@@ -412,7 +414,8 @@ def make_slab_round_step(loss_fn: LossFn, channel_cfg: OTAChannelConfig,
         under downlink="int8" (the server always keeps the master)."""
         if not dl_int8:
             return state.w
-        r = downlink_sr_slab_inputs(key, state.spec.padded)
+        with jax.named_scope(obs.DRAWS):
+            r = downlink_sr_slab_inputs(key, state.spec.padded)
         return downlink_quantize_slab(state.w, r)
 
     if fl_cfg.dynamic_round:
@@ -421,7 +424,8 @@ def make_slab_round_step(loss_fn: LossFn, channel_cfg: OTAChannelConfig,
         def step(state: SlabTrainState, key, client_batches=None):
             _check_ef_state(state)
             spec = state.spec
-            params = slab_to_tree(spec, _broadcast_slab(state, key))
+            with jax.named_scope(obs.BROADCAST):
+                params = slab_to_tree(spec, _broadcast_slab(state, key))
             parts = streamed_round_parts(
                 key, channel_cfg, fl_cfg, spec, client_fn, params,
                 client_batches=client_batches, batch_gen=batch_gen,
@@ -435,50 +439,56 @@ def make_slab_round_step(loss_fn: LossFn, channel_cfg: OTAChannelConfig,
             # selects are omitted entirely (a dead ``where`` changes
             # how XLA fuses the update kernel, costing the chunk >= N
             # bitwise contract).
-            can_skip = fl_cfg.dynamic_norm
-            participated = parts.norm > 0.0
-            if track:
-                a_new = update_alpha_ema(state.alpha_hat, parts.stats,
-                                         adaptive_cfg.alpha_ema)
-                alpha_hat = (jnp.where(participated, a_new, state.alpha_hat)
-                             if can_skip else a_new)
-                alpha_arg = effective_alpha(alpha_hat)
-                alpha_metric = alpha_hat
-            else:
-                alpha_hat = state.alpha_hat
-                alpha_arg = None
-                alpha_metric = jnp.asarray(adaptive_cfg.alpha, jnp.float32)
+            with jax.named_scope(obs.UPDATE):
+                can_skip = fl_cfg.dynamic_norm
+                participated = parts.norm > 0.0
+                if track:
+                    a_new = update_alpha_ema(state.alpha_hat, parts.stats,
+                                             adaptive_cfg.alpha_ema)
+                    alpha_hat = (jnp.where(participated, a_new,
+                                           state.alpha_hat)
+                                 if can_skip else a_new)
+                    alpha_arg = effective_alpha(alpha_hat)
+                    alpha_metric = alpha_hat
+                else:
+                    alpha_hat = state.alpha_hat
+                    alpha_arg = None
+                    alpha_metric = jnp.asarray(adaptive_cfg.alpha,
+                                               jnp.float32)
             w_in = state.w
             if any(dt != jnp.float32 for dt in spec.dtypes):
                 # The round-trip mirrors the pytree backends' per-round
                 # storage-dtype cast; under the int8 downlink the cast
                 # still applies to the MASTER weights (the update never
                 # consumes the quantized broadcast).
-                w_in = tree_to_slab(spec, params if not dl_int8
-                                    else slab_to_tree(spec, state.w))
-            new_opt, w_new = slab_update_slabs(adaptive_cfg, parts.g_slab,
-                                               state.opt, w_in,
-                                               alpha=alpha_arg)
-            ef_next = parts.ef_new[None] if use_ef else state.ef
-            if can_skip:
-                w_new = jnp.where(participated, w_new, state.w)
-                new_opt = tuple(jnp.where(participated, o_n, o_o)
-                                for o_n, o_o in zip(new_opt, state.opt))
-                if use_ef:
-                    # A dead round transmits nothing: the residual of a
-                    # transmission that never happened must not replace
-                    # the carried one.
-                    ef_next = jnp.where(participated, ef_next, state.ef)
-            nf = jnp.maximum(parts.n_participants, 1.0)
-            metrics = RoundMetrics(
-                loss=parts.loss_sum / nf,
-                grad_norm=jnp.sqrt(jnp.sum(jnp.square(
-                    parts.clean_slab / nf))),
-                noisy_grad_norm=jnp.sqrt(jnp.sum(jnp.square(parts.g_slab))),
-                fading_mean=jnp.mean(parts.h),
-                alpha_hat=alpha_metric,
-                n_participants=parts.n_participants,
-            )
+                with jax.named_scope(obs.CAST):
+                    w_in = tree_to_slab(spec, params if not dl_int8
+                                        else slab_to_tree(spec, state.w))
+            with jax.named_scope(obs.UPDATE):
+                new_opt, w_new = slab_update_slabs(adaptive_cfg,
+                                                   parts.g_slab, state.opt,
+                                                   w_in, alpha=alpha_arg)
+                ef_next = parts.ef_new[None] if use_ef else state.ef
+                if can_skip:
+                    w_new = jnp.where(participated, w_new, state.w)
+                    new_opt = tuple(jnp.where(participated, o_n, o_o)
+                                    for o_n, o_o in zip(new_opt, state.opt))
+                    if use_ef:
+                        # A dead round transmits nothing: the residual of
+                        # a transmission that never happened must not
+                        # replace the carried one.
+                        ef_next = jnp.where(participated, ef_next, state.ef)
+                nf = jnp.maximum(parts.n_participants, 1.0)
+                metrics = RoundMetrics(
+                    loss=parts.loss_sum / nf,
+                    grad_norm=jnp.sqrt(jnp.sum(jnp.square(
+                        parts.clean_slab / nf))),
+                    noisy_grad_norm=jnp.sqrt(jnp.sum(jnp.square(
+                        parts.g_slab))),
+                    fading_mean=jnp.mean(parts.h),
+                    alpha_hat=alpha_metric,
+                    n_participants=parts.n_participants,
+                )
             return SlabTrainState(state.step + 1, w_new, new_opt, alpha_hat,
                                   spec, ef_next), metrics
 
@@ -757,7 +767,8 @@ def run_rounds_slab(run_chunk, state: SlabTrainState, key, batch_fn,
                     log_every: int = 0, log=print,
                     key_fn: Optional[Callable] = None, start_round: int = 0,
                     chunk_hook: Optional[Callable] = None,
-                    align: Tuple[int, ...] = ()):
+                    align: Tuple[int, ...] = (),
+                    profile_dir: Optional[str] = None):
     """Slab-resident twin of ``run_rounds`` (host driver).
 
     ``run_chunk`` comes from ``make_slab_round_runner``. Rounds are
@@ -776,6 +787,13 @@ def run_rounds_slab(run_chunk, state: SlabTrainState, key, batch_fn,
     and every multiple of each period in ``align`` — IS a boundary.
     ``chunk_hook(t, state, history)`` runs after every chunk (e.g. for
     checkpointing). Returns ``(state, history)``.
+
+    Each chunk runs under the host spans of ``repro.obs``: ``fl.chunk``
+    (a step annotation numbered by the chunk's first round) around
+    ``fl.keys``, ``fl.batch``, ``fl.dispatch``, ``fl.sync``,
+    ``fl.history`` and ``fl.hook``. ``profile_dir`` writes a profiler
+    trace of this call's second chunk, the first one whose program is
+    already compiled, to that directory.
     """
     if eval_fn is not None and adaptive_cfg is None:
         raise ValueError("eval_fn needs adaptive_cfg= to materialise params "
@@ -787,46 +805,59 @@ def run_rounds_slab(run_chunk, state: SlabTrainState, key, batch_fn,
     history = []
     dead = _DeadRoundAggregator(log)
     t = start_round
+    n_chunks = 0
     while t < n_rounds:
         r = min(chunk, n_rounds - t)
         for period in (eval_every, *align):
             if period:
                 r = min(r, period - t % period)
-        ks, bs = [], []
-        for i in range(r):
-            if key_fn is not None:
-                k_round, k_data = key_fn(t + i), None
-            else:
-                key, k_round, k_data = jax.random.split(key, 3)
-            ks.append(k_round)
-            bs.append(batch_fn(t + i, k_data))
-        state, ms = run_chunk(state, jnp.stack(ks),
-                              jax.tree.map(lambda *xs: jnp.stack(xs), *bs))
-        loss = jax.device_get(ms.loss)
-        gn = jax.device_get(ms.grad_norm)
-        ngn = jax.device_get(ms.noisy_grad_norm)
-        ah = jax.device_get(ms.alpha_hat)
-        np_ = jax.device_get(ms.n_participants)
-        for i in range(r):
-            history.append({"round": t + i, "loss": float(loss[i]),
-                            "grad_norm": float(gn[i]),
-                            "noisy_grad_norm": float(ngn[i]),
-                            "alpha_hat": float(ah[i]),
-                            "n_participants": float(np_[i])})
-            if float(np_[i]) == 0.0:
-                dead.record(t + i)
-        t += r
-        if eval_fn is not None and eval_every and t % eval_every == 0:
-            params, _ = unpack_train_state(adaptive_cfg, state)
-            history[-1].update(eval_fn(params))
-        if log_every:
-            for i in range(t - r, t):
-                if (i + 1) % log_every == 0:
-                    dead.flush()
-                    # history is indexed from start_round, i is absolute
-                    _log_round(log, i, history[i - start_round])
-        if chunk_hook is not None:
-            chunk_hook(t, state, history)
+        profiled = profile_dir is not None and n_chunks == 1
+        with (jax.profiler.trace(profile_dir) if profiled
+              else contextlib.nullcontext()), obs.span(obs.CHUNK, step=t):
+            with obs.span(obs.KEYS):
+                ks, kds = [], []
+                for i in range(r):
+                    if key_fn is not None:
+                        k_round, k_data = key_fn(t + i), None
+                    else:
+                        key, k_round, k_data = jax.random.split(key, 3)
+                    ks.append(k_round)
+                    kds.append(k_data)
+                keys = jnp.stack(ks)
+            with obs.span(obs.BATCH):
+                bs = [batch_fn(t + i, kds[i]) for i in range(r)]
+                batches = jax.tree.map(lambda *xs: jnp.stack(xs), *bs)
+            with obs.span(obs.DISPATCH):
+                state, ms = run_chunk(state, keys, batches)
+            with obs.span(obs.SYNC):
+                loss = jax.device_get(ms.loss)
+                gn = jax.device_get(ms.grad_norm)
+                ngn = jax.device_get(ms.noisy_grad_norm)
+                ah = jax.device_get(ms.alpha_hat)
+                np_ = jax.device_get(ms.n_participants)
+            with obs.span(obs.HISTORY):
+                for i in range(r):
+                    history.append({"round": t + i, "loss": float(loss[i]),
+                                    "grad_norm": float(gn[i]),
+                                    "noisy_grad_norm": float(ngn[i]),
+                                    "alpha_hat": float(ah[i]),
+                                    "n_participants": float(np_[i])})
+                    if float(np_[i]) == 0.0:
+                        dead.record(t + i)
+            t += r
+            n_chunks += 1
+            if eval_fn is not None and eval_every and t % eval_every == 0:
+                params, _ = unpack_train_state(adaptive_cfg, state)
+                history[-1].update(eval_fn(params))
+            if log_every:
+                for i in range(t - r, t):
+                    if (i + 1) % log_every == 0:
+                        dead.flush()
+                        # history is indexed from start_round, i is absolute
+                        _log_round(log, i, history[i - start_round])
+            if chunk_hook is not None:
+                with obs.span(obs.HOOK):
+                    chunk_hook(t, state, history)
     dead.flush()
     return state, history
 

@@ -55,6 +55,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.channel import (OTAChannelConfig, sample_fading,
                                 sr_kernel_seed)
 from repro.core.ota import (_interference_slab_inputs, restore_zero_tail,
@@ -167,35 +168,35 @@ def streamed_round_parts(key: jax.Array, channel_cfg: OTAChannelConfig,
         raise ValueError("ef= (error feedback) needs a quantized uplink; "
                          'the "f32" payload has no residual')
 
-    mask, gain = round_participation(key, fl_cfg)
     dynamic_norm = fl_cfg.dynamic_norm
-    kh, kx = jax.random.split(key)
-    h = sample_fading(kh, cfg, (n,))
-    # Participation and weights fold into the EFFECTIVE fading, next to
-    # power control; with neither active h_eff is h * 1.0 == h bitwise
-    # and the static 1/N divisor stays in-kernel.
-    h_eff = h * gain if dynamic_norm else h
     n_div = 1 if dynamic_norm else n
-    # Ragged final chunk: pad the PER-ROW operands (effective fading,
-    # mask) with zero rows up to the next chunk multiple. The padded
-    # rows transmit with zero gain and count for nothing; the draws
-    # above were taken at full (N,) BEFORE padding, so the PRNG stream
-    # is untouched. When chunk | N this is a no-op (zero-length pad),
-    # keeping the divisible path bitwise-identical.
     n_chunks = -(-n // chunk)
     n_padded = n_chunks * chunk
-    if n_padded != n:
-        h_sched = jnp.pad(h_eff, (0, n_padded - n))
-        mask_sched = jnp.pad(mask, (0, n_padded - n))
-    else:
-        h_sched, mask_sched = h_eff, mask
+    with jax.named_scope(obs.DRAWS):
+        mask, gain = round_participation(key, fl_cfg)
+        kh, kx = jax.random.split(key)
+        h = sample_fading(kh, cfg, (n,))
+        # Participation and weights fold into the EFFECTIVE fading, next
+        # to power control; with neither active h_eff is h * 1.0 == h
+        # bitwise and the static 1/N divisor stays in-kernel.
+        h_eff = h * gain if dynamic_norm else h
+        # Ragged final chunk: pad the PER-ROW operands (effective fading,
+        # mask) with zero rows up to the next chunk multiple. The padded
+        # rows transmit with zero gain and count for nothing; the draws
+        # above were taken at full (N,) BEFORE padding, so the PRNG
+        # stream is untouched. When chunk | N this is a no-op
+        # (zero-length pad), keeping the divisible path bitwise-identical.
+        if n_padded != n:
+            h_sched = jnp.pad(h_eff, (0, n_padded - n))
+            mask_sched = jnp.pad(mask, (0, n_padded - n))
+        else:
+            h_sched, mask_sched = h_eff, mask
 
-    if use_kernels:
-        def transmit(g_stack, h_c, acc):
-            return ota_transmit_slab(g_stack, h_c, n_total=n_div, acc=acc,
-                                     interpret=cfg.interpret)
-    else:
-        def transmit(g_stack, h_c, acc):
+    def transmit(g_stack, h_c, acc):
+        with jax.named_scope(obs.TRANSMIT):
+            if use_kernels:
+                return ota_transmit_slab(g_stack, h_c, n_total=n_div,
+                                         acc=acc, interpret=cfg.interpret)
             return ota_transmit_ref(g_stack, h_c, n_total=n_div, acc=acc)
 
     ragged = n_padded != n
@@ -205,33 +206,37 @@ def streamed_round_parts(key: jax.Array, channel_cfg: OTAChannelConfig,
         SLOT of the double-buffered pipeline (everything chunk c
         contributes, before any accumulator is touched)."""
         start = c * chunk
-        idx = start + jnp.arange(chunk)
-        if ragged:
-            # Padding rows re-read row N-1; its gradient lands with the
-            # zero gain/mask of the padded schedule rows, so it folds
-            # exactly 0.0 into every accumulator.
-            idx = jnp.minimum(idx, n - 1)
-        if batch_gen is not None:
-            batch = batch_gen(key, idx)
-        elif ragged:
-            batch = jax.tree.map(lambda b: jnp.take(b, idx, axis=0),
-                                 client_batches)
-        else:
-            batch = jax.tree.map(
-                lambda b: jax.lax.dynamic_slice_in_dim(b, start, chunk),
-                client_batches)
-        grads, losses = jax.vmap(client_fn, in_axes=(None, 0))(params, batch)
-        g_stack = stack_to_slab(spec, grads)
-        h_c = jax.lax.dynamic_slice_in_dim(h_sched, start, chunk)
-        m_c = jax.lax.dynamic_slice_in_dim(mask_sched, start, chunk)
+        with jax.named_scope(obs.CLIENT):
+            idx = start + jnp.arange(chunk)
+            if ragged:
+                # Padding rows re-read row N-1; its gradient lands with
+                # the zero gain/mask of the padded schedule rows, so it
+                # folds exactly 0.0 into every accumulator.
+                idx = jnp.minimum(idx, n - 1)
+            if batch_gen is not None:
+                batch = batch_gen(key, idx)
+            elif ragged:
+                batch = jax.tree.map(lambda b: jnp.take(b, idx, axis=0),
+                                     client_batches)
+            else:
+                batch = jax.tree.map(
+                    lambda b: jax.lax.dynamic_slice_in_dim(b, start, chunk),
+                    client_batches)
+            grads, losses = jax.vmap(client_fn, in_axes=(None, 0))(params,
+                                                                   batch)
+        with jax.named_scope(obs.PACK):
+            g_stack = stack_to_slab(spec, grads)
+            h_c = jax.lax.dynamic_slice_in_dim(h_sched, start, chunk)
+            m_c = jax.lax.dynamic_slice_in_dim(mask_sched, start, chunk)
         return g_stack, h_c, m_c, losses
 
     def body(carry, c):
         acc, clean, loss_sum = carry
         g_stack, h_c, m_c, losses = produce(c)
         acc = transmit(g_stack, h_c, acc)
-        clean = clean + jnp.sum(m_c[:, None] * g_stack, axis=0)
-        loss_sum = loss_sum + jnp.sum(m_c * losses)
+        with jax.named_scope(obs.PACK):
+            clean = clean + jnp.sum(m_c[:, None] * g_stack, axis=0)
+            loss_sum = loss_sum + jnp.sum(m_c * losses)
         return (acc, clean, loss_sum), None
 
     def fold(carry, slot):
@@ -243,10 +248,11 @@ def streamed_round_parts(key: jax.Array, channel_cfg: OTAChannelConfig,
         tolerance-tier trade of ``FLConfig.double_buffer``)."""
         acc, clean, loss_sum = carry
         g_stack, h_c, m_c, losses = slot
-        coeff = jnp.stack([h_c * (1.0 / n_div), m_c])
-        both = coeff @ g_stack
-        return (acc + both[0], clean + both[1],
-                loss_sum + jnp.sum(m_c * losses))
+        with jax.named_scope(obs.PACK):
+            coeff = jnp.stack([h_c * (1.0 / n_div), m_c])
+            both = coeff @ g_stack
+            return (acc + both[0], clean + both[1],
+                    loss_sum + jnp.sum(m_c * losses))
 
     def db_body(carry, c):
         """Two-slot pipeline step: issue chunk c's client compute, then
@@ -258,7 +264,8 @@ def streamed_round_parts(key: jax.Array, channel_cfg: OTAChannelConfig,
         acc, clean, loss_sum = fold((acc, clean, loss_sum), slot)
         return (acc, clean, loss_sum, new_slot), None
 
-    zeros = jnp.zeros((spec.padded,), jnp.float32)
+    with jax.named_scope(obs.PACK):
+        zeros = jnp.zeros((spec.padded,), jnp.float32)
     # One chunk, static 1/N, f32 wire: the round IS the resident round,
     # so the finish below launches the resident channel kernel on the
     # whole stack instead of accumulating and re-launching one row.
@@ -272,13 +279,17 @@ def streamed_round_parts(key: jax.Array, channel_cfg: OTAChannelConfig,
         # fuses the client-gradient graph, costing the bitwise
         # contract), just the resident compute feeding the
         # accumulating kernel once.
-        batch = (batch_gen(key, jnp.arange(n)) if batch_gen is not None
-                 else client_batches)
-        grads, losses = jax.vmap(client_fn, in_axes=(None, 0))(params, batch)
-        g_stack = stack_to_slab(spec, grads)
+        with jax.named_scope(obs.CLIENT):
+            batch = (batch_gen(key, jnp.arange(n)) if batch_gen is not None
+                     else client_batches)
+            grads, losses = jax.vmap(client_fn, in_axes=(None, 0))(params,
+                                                                   batch)
+        with jax.named_scope(obs.PACK):
+            g_stack = stack_to_slab(spec, grads)
         acc = None if resident_mac else transmit(g_stack, h_eff, zeros)
-        clean = jnp.sum(mask[:, None] * g_stack, axis=0)
-        loss_sum = jnp.sum(mask * losses)
+        with jax.named_scope(obs.PACK):
+            clean = jnp.sum(mask[:, None] * g_stack, axis=0)
+            loss_sum = jnp.sum(mask * losses)
     elif fl_cfg.double_buffer:
         # Prologue: chunk 0 fills the slot before the pipeline starts;
         # steady state overlaps produce(c) with fold(c-1); the epilogue
@@ -295,24 +306,26 @@ def streamed_round_parts(key: jax.Array, channel_cfg: OTAChannelConfig,
                                 jnp.arange(n_chunks, dtype=jnp.int32))
         acc, clean, loss_sum = carry
 
-    n_part = jnp.sum(mask)
-    norm = jnp.sum(gain) if dynamic_norm else n_part
-    if dynamic_norm:
-        # Zero-participation guard: a dead round divides by 1 (the
-        # partial is all-zero anyway) and the round step SKIPS the
-        # server update; max(norm, 1) would instead corrupt legitimate
-        # fractional-weight rounds.
-        norm_safe = jnp.where(norm > 0.0, norm, 1.0)
-        g_pre = acc / norm_safe
-    else:
-        g_pre = acc
+    with jax.named_scope(obs.CHANNEL):
+        n_part = jnp.sum(mask)
+        norm = jnp.sum(gain) if dynamic_norm else n_part
+        if dynamic_norm:
+            # Zero-participation guard: a dead round divides by 1 (the
+            # partial is all-zero anyway) and the round step SKIPS the
+            # server update; max(norm, 1) would instead corrupt
+            # legitimate fractional-weight rounds.
+            norm_safe = jnp.where(norm > 0.0, norm, 1.0)
+            g_pre = acc / norm_safe
+        else:
+            g_pre = acc
 
     # Finish: the round crosses the channel through the SAME fused
     # kernels as the resident round. With ``resident_mac`` that is the
     # resident launch itself, on the whole client stack. Otherwise the
     # completed partial goes as a single transmitter row — sum(1 * x)/1
     # == x exactly, so the op order of the resident launch is kept.
-    u, e, scale = _interference_slab_inputs(kx, cfg, spec)
+    with jax.named_scope(obs.DRAWS):
+        u, e, scale = _interference_slab_inputs(kx, cfg, spec)
     one = jnp.ones((1,), jnp.float32)
     stats = None
     ef_new = None
@@ -323,59 +336,65 @@ def streamed_round_parts(key: jax.Array, channel_cfg: OTAChannelConfig,
         stochastic = cfg.uplink.stochastic_rounding and qmode == "int8"
         inkernel = (stochastic and cfg.uplink.sr_inkernel and use_kernels
                     and not resolve_interpret(cfg.interpret))
-        r = (uplink_sr_slab_inputs(key, spec)[0]
-             if stochastic and not inkernel else None)
+        with jax.named_scope(obs.DRAWS):
+            r = (uplink_sr_slab_inputs(key, spec)[0]
+                 if stochastic and not inkernel else None)
         want_ef = ef is not None
         if use_kernels:
-            sr_seed = sr_kernel_seed(key)[0] if inkernel else None
-            tx = ota_transmit_slab(g_pre[None], one, n_total=1,
-                                   quantize=True, r=r,
-                                   stochastic=stochastic, qmode=qmode,
-                                   zero_fold=zero_fold, sr_seed=sr_seed,
-                                   ef=ef, return_residual=want_ef,
-                                   interpret=cfg.interpret)
-            payload = (pack_sign_slab(tx[0][None],
-                                      planes=(packed == "planes"))
-                       if packed else tx[0][None])
-            g_slab = ota_receive_slab(payload, tx[1][None], u, e,
-                                      alpha=cfg.alpha, scale=scale,
-                                      packed=packed,
-                                      pilot_stats=pilot_stats,
-                                      interpret=cfg.interpret)
+            with jax.named_scope(obs.DRAWS):
+                sr_seed = sr_kernel_seed(key)[0] if inkernel else None
+            with jax.named_scope(obs.CHANNEL):
+                tx = ota_transmit_slab(g_pre[None], one, n_total=1,
+                                       quantize=True, r=r,
+                                       stochastic=stochastic, qmode=qmode,
+                                       zero_fold=zero_fold, sr_seed=sr_seed,
+                                       ef=ef, return_residual=want_ef,
+                                       interpret=cfg.interpret)
+                payload = (pack_sign_slab(tx[0][None],
+                                          planes=(packed == "planes"))
+                           if packed else tx[0][None])
+                g_slab = ota_receive_slab(payload, tx[1][None], u, e,
+                                          alpha=cfg.alpha, scale=scale,
+                                          packed=packed,
+                                          pilot_stats=pilot_stats,
+                                          interpret=cfg.interpret)
         else:
-            tx = ota_transmit_ref(g_pre[None], one, n_total=1,
-                                  quantize=True, r=r,
-                                  stochastic=stochastic, qmode=qmode,
-                                  zero_fold=zero_fold,
-                                  ef=ef, return_residual=want_ef)
-            payload = (pack_sign_slab(tx[0][None],
-                                      planes=(packed == "planes"))
-                       if packed else tx[0][None])
-            g_slab = ota_receive_ref(payload, tx[1][None], u, e,
-                                     alpha=cfg.alpha, scale=scale,
-                                     packed=packed,
-                                     pilot_stats=pilot_stats)
+            with jax.named_scope(obs.CHANNEL):
+                tx = ota_transmit_ref(g_pre[None], one, n_total=1,
+                                      quantize=True, r=r,
+                                      stochastic=stochastic, qmode=qmode,
+                                      zero_fold=zero_fold,
+                                      ef=ef, return_residual=want_ef)
+                payload = (pack_sign_slab(tx[0][None],
+                                          planes=(packed == "planes"))
+                           if packed else tx[0][None])
+                g_slab = ota_receive_ref(payload, tx[1][None], u, e,
+                                         alpha=cfg.alpha, scale=scale,
+                                         packed=packed,
+                                         pilot_stats=pilot_stats)
         if want_ef:
             ef_new = tx[2]
     else:
         rows, h_rows, n_rows = ((g_stack, h_eff, n) if resident_mac
                                 else (g_pre[None], one, 1))
-        if use_kernels:
-            g_slab = ota_channel_slab(rows, h_rows, u, e,
-                                      alpha=cfg.alpha, scale=scale,
-                                      n_total=n_rows,
-                                      pilot_stats=pilot_stats,
-                                      interpret=cfg.interpret)
-        else:
-            g_slab = ota_channel_ref(rows, h_rows, u, e,
-                                     alpha=cfg.alpha, scale=scale,
-                                     n_total=n_rows,
-                                     pilot_stats=pilot_stats)
+        with jax.named_scope(obs.CHANNEL):
+            if use_kernels:
+                g_slab = ota_channel_slab(rows, h_rows, u, e,
+                                          alpha=cfg.alpha, scale=scale,
+                                          n_total=n_rows,
+                                          pilot_stats=pilot_stats,
+                                          interpret=cfg.interpret)
+            else:
+                g_slab = ota_channel_ref(rows, h_rows, u, e,
+                                         alpha=cfg.alpha, scale=scale,
+                                         n_total=n_rows,
+                                         pilot_stats=pilot_stats)
     if pilot_stats:
         g_slab, stats = g_slab
     if cfg.uplink.quantized and cfg.uplink.zero_fold:
-        g_slab = restore_zero_tail(g_slab, spec)
-        ef_new = restore_zero_tail(ef_new, spec)
+        with jax.named_scope(obs.CHANNEL):
+            g_slab = restore_zero_tail(g_slab, spec)
+            ef_new = restore_zero_tail(ef_new, spec)
 
     return StreamParts(g_slab=g_slab, h=h, mask=mask,
                        n_participants=n_part, norm=norm,

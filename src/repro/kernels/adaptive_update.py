@@ -54,6 +54,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import obs
+
 # The single source of the |.|^alpha zero-guard: the jnp/pallas backend
 # parity contract depends on the kernel computing the exact same ops as
 # the tree.map reference. (Import is cycle-safe: core.adaptive pulls in
@@ -194,6 +196,7 @@ def adaptive_update_slab(g: jax.Array, delta: Optional[jax.Array],
                    ] * n_state
         + [jax.ShapeDtypeStruct((rows_pad, LANE), w.dtype)],
         interpret=interpret,
+        name=obs.ADAPTIVE_UPDATE,
     )(*ins)
     if not isinstance(outs, (list, tuple)):
         outs = [outs]

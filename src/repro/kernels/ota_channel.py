@@ -138,6 +138,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.core.channel import cms_transform
 from repro.kernels.interpret import coarse_block, resolve_interpret
 
@@ -247,6 +248,7 @@ def ota_channel_slab(grads: jax.Array, h: jax.Array, u: jax.Array,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name=obs.OTA_CHANNEL,
     )(gp, h2, up, ep)
     if pilot_stats:
         return outs[0].reshape(-1)[:d], _sum_stats_rows(outs[1])
@@ -444,6 +446,7 @@ def ota_transmit_slab(grads: jax.Array, h: jax.Array, *,
                 out_specs=pl.BlockSpec((1, block_cols), lambda i: (0, i)),
                 out_shape=jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
                 interpret=interpret,
+                name=obs.OTA_TRANSMIT,
             )(gp, h2)
             return out.reshape(-1)[:d]
 
@@ -473,6 +476,7 @@ def ota_transmit_slab(grads: jax.Array, h: jax.Array, *,
             out_specs=pl.BlockSpec((1, block_cols), lambda j, r_: (0, j)),
             out_shape=jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
             interpret=interpret,
+            name=obs.OTA_TRANSMIT_STREAM,
         )(gp, hp, ap)
         return out.reshape(-1)[:d]
 
@@ -553,6 +557,7 @@ def ota_transmit_slab(grads: jax.Array, h: jax.Array, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name=obs.OTA_TRANSMIT_QUANT,
     )(*operands)
     q, s = outs[0], outs[1]
     ret = (q.reshape(-1)[:d], s.reshape(-1)[:d // LANE])
@@ -710,6 +715,7 @@ def ota_receive_slab(payload: jax.Array, scales: jax.Array, u: jax.Array,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        name=obs.OTA_RECEIVE,
     )(qp, sp, up, ep)
     if pilot_stats:
         return outs[0].reshape(-1)[:d], _sum_stats_rows(outs[1])

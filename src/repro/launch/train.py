@@ -79,6 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.checkpoint as ckpt
+from repro import obs
 from repro.configs import ARCHS, get_config, smoke_config
 from repro.core import (AdaptiveConfig, FLConfig, OTAChannelConfig,
                         UplinkConfig, donation_report, init_train_state,
@@ -142,6 +143,22 @@ def kernel_mode(backend: str, interpret: bool) -> str:
         return (f"pallas kernels: INTERPRET mode on tpu "
                 f"({INTERPRET_ENV}={os.environ.get(INTERPRET_ENV)})")
     return f"pallas kernels: interpret mode (no TPU; jax backend {platform})"
+
+
+def profile_line(profile_dir: str, n_chunks: int) -> str:
+    """Where ``--profile`` wrote its trace, with the host time of each
+    span of the traced chunk (``repro.obs.SPAN_RECORD``)."""
+    if n_chunks < 2:
+        return (f"profile: nothing traced, the run had {n_chunks} chunk(s) "
+                f"and the trace is of the second")
+    rec = list(obs.SPAN_RECORD)
+    chunk = [(s, e) for n, s, e in rec if n == obs.CHUNK][-1]
+    ms = {}
+    for n, s, e in rec:
+        if chunk[0] <= s and e <= chunk[1]:
+            ms[n] = ms.get(n, 0.0) + (e - s) * 1e-6
+    return (f"profile: chunk 2 traced to {profile_dir}; host spans (ms): "
+            + ", ".join(f"{n} {ms[n]:.3f}" for n in obs.SPANS if n in ms))
 
 
 def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
@@ -292,6 +309,10 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
                          "boundaries)")
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--history-out", default=None)
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="write a profiler trace of the second scan chunk "
+                         "(the first after the one that compiles) to DIR; "
+                         "bench/inspect_trace.py DIR reads it")
     args = ap.parse_args(argv)
     if args.resume and not args.ckpt_dir:
         ap.error("--resume needs --ckpt-dir")
@@ -493,12 +514,22 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
     t0 = time.time()
     chunk_seconds = []
     t_chunk = [t0, start_round]
+    compiles = obs.CompileCounter()
+    built = [0]
 
     def chunk_hook(t, st, history):
         # The history of the chunk was read from the device before this
         # hook runs, so the wall time covers the chunk's work.
         now = time.time()
         chunk_seconds.append((t - t_chunk[1], now - t_chunk[0]))
+        # A chunk after the first that builds a program (compiles it or
+        # loads it from the cache) is named, so a recompile is seen.
+        if len(chunk_seconds) > 1 and compiles.programs() > built[0]:
+            print(f"chunk {len(chunk_seconds)} (rounds {t_chunk[1] + 1}-{t})"
+                  f" built {compiles.programs() - built[0]} program(s): "
+                  f"{compiles.compiles} compiled, {compiles.hits} from the "
+                  f"compile cache since the loop started", flush=True)
+        built[0] = compiles.programs()
         t_chunk[:] = [now, t]
         # run_rounds_slab clips chunks to the align periods, so every
         # log/checkpoint multiple lands exactly on a chunk boundary.
@@ -514,12 +545,16 @@ def main(argv: Optional[Sequence[str]] = None) -> TrainRun:
                                               f"round_{t}.npz"), st,
                                  blocking=not args.ckpt_async)
 
-    state, history = run_rounds_slab(
-        run_chunk, state, None, batch_fn, args.rounds,
-        chunk=args.scan_rounds,
-        key_fn=lambda t: jax.random.fold_in(base_key, t),
-        start_round=start_round, chunk_hook=chunk_hook,
-        align=(args.log_every, args.ckpt_every if args.ckpt_dir else 0))
+    with compiles:
+        state, history = run_rounds_slab(
+            run_chunk, state, None, batch_fn, args.rounds,
+            chunk=args.scan_rounds,
+            key_fn=lambda t: jax.random.fold_in(base_key, t),
+            start_round=start_round, chunk_hook=chunk_hook,
+            align=(args.log_every, args.ckpt_every if args.ckpt_dir else 0),
+            profile_dir=args.profile)
+    if args.profile:
+        print(profile_line(args.profile, len(chunk_seconds)))
     if args.ckpt_async:
         ckpt.wait_for_async_saves()
     if args.history_out:

@@ -129,9 +129,17 @@ def slab_to_tree(spec: SlabSpec, slab: jax.Array, cast: bool = True) -> PyTree:
 
 def stack_to_slab(spec: SlabSpec, tree: PyTree,
                   dtype=jnp.float32) -> jax.Array:
-    """Flatten a client-stacked tree (leaves ``(N, *shape)``) to (N, padded)."""
+    """Flatten a client-stacked tree (leaves ``(N, *shape)``) to (N, padded).
+
+    A one-client stack is the flat slab with a unit axis in front: a TPU
+    then stores it as it stores the flat slab, where a one-row
+    concatenation is tiled (1, 128) and costs a relayout pass wherever
+    the slab's own layout is read."""
     leaves = spec.treedef.flatten_up_to(tree)
     n = leaves[0].shape[0]
+    if n == 1:
+        one = jax.tree.unflatten(spec.treedef, [l[0] for l in leaves])
+        return tree_to_slab(spec, one, dtype)[None]
     flat = jnp.concatenate(
         [jnp.asarray(l).reshape(n, -1).astype(dtype) for l in leaves], axis=1)
     return jnp.pad(flat, ((0, 0), (0, spec.padded - spec.total)))

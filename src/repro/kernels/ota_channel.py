@@ -55,23 +55,37 @@ where the transform reduces to the finite Gaussian 2*sin(u)*sqrt(e))
 and the Exp(1) draws are floored. The tail index is validated against
 the same (1, 2] range as ``OTAChannelConfig``.
 
-Grid: 1-D over column blocks of size (N, block_cols); the N reduction
-runs inside the tile (N = clients-per-shard is small, <= a few hundred).
+Grid: the f32 channel and the streamed transmit view the slab as lane
+rows — a (d,) vector as (d / 128, 128), an (N, d) stack as (N, d / 128,
+128), views that cost nothing on a TPU for a vector or a one-row stack
+(every slab has d % 128 == 0); a several-row stack is stored tiled
+across its rows and is copied row by row — and tile them in (N,
+block_rows, 128) and (block_rows, 128) blocks, 1-D over row blocks; the
+N reduction runs inside the tile. The block rows follow from the launch's shapes
+(``mac_block_rows``: as many rows as the VMEM budget holds, a few
+thousand for one client a chunk; a launch of more than ~1,000 client
+rows is refused), and the last row block may be ragged: the math is per
+coordinate, the rows past the end are never written back, and the
+pilot-statistics epilogue masks them. No slab-sized pad or slice
+surrounds a one-row launch. The other launches (resident and quantized
+transmit, receive) keep blocks ``block_cols`` coordinates wide, padded
+to a block multiple.
 ``interpret=None`` auto-selects Pallas interpret mode from the platform
 (``repro.kernels.interpret``): compiled on TPU, interpreted elsewhere.
 
 **Streamed client axis** (PR 6): with ``acc=`` and/or ``row_chunk=`` the
-transmit grid gains a CLIENT-CHUNK dimension — ``(col_blocks,
-row_chunks)``, column blocks outer so each output tile is revisited
+transmit grid gains a CLIENT-CHUNK dimension — ``(row_blocks,
+row_chunks)``, row blocks outer so each output tile is revisited
 consecutively — and the kernel accumulates the faded partial sum
 in-place across the row chunks (``@pl.when(r == 0)`` seeds the output
 tile from the ``acc`` carry, every step adds its chunk's
-``sum_rows(h*g)/n_total``). ``acc`` chains launches: a round streams N
-clients as a ``lax.scan`` over gradient chunks, each chunk's transmit
-launch folding into the running (d,) partial — peak memory is
-O(chunk * d) regardless of N, and ``n_total`` keeps the 1/N wire
-normalisation identical to the resident launch. With one row chunk the
-accumulation is ``0 + sum(h*g)/n_total`` — bitwise-equal to the
+``sum_rows(h*g)/n_total``). The ``acc`` operand is aliased to the
+output, so a scan carry is updated in place. ``acc`` chains launches: a
+round streams N clients as a ``lax.scan`` over gradient chunks, each
+chunk's transmit launch folding into the running (d,) partial — peak
+memory is O(chunk * d) regardless of N, and ``n_total`` keeps the 1/N
+wire normalisation identical to the resident launch. With one row chunk
+the accumulation is ``0 + sum(h*g)/n_total`` — bitwise-equal to the
 resident kernel — so streaming with ``chunk >= N`` is a pure memory
 optimization (the parity guard in tests/test_stream.py pins this).
 Quantization composes by accumulating the f32 partial first and
@@ -140,14 +154,80 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro import obs
 from repro.core.channel import cms_transform
-from repro.kernels.interpret import coarse_block, resolve_interpret
+from repro.kernels.interpret import (INTERPRET_BLOCK_CAP, coarse_block,
+                                     resolve_interpret)
 
 LANE = 128
+SUBLANE = 8
 DEFAULT_BLOCK_COLS = 512
+# Scoped VMEM a lane-dense f32 MAC launch may fill with its
+# (block_rows, LANE) tiles, of the 16 MiB default on a TPU v5e.
+MAC_VMEM_BYTES = 12 << 20
+# (block_rows, LANE) f32 temporaries the CMS transform keeps in VMEM
+# (read off the compiler's scoped-VMEM report for a v5e). The reduction
+# over a tile's client rows keeps one more for each row past the first
+# (read off its refusals of 2 to 128 rows).
+CMS_TEMP_TILES = 5
 INT8_MAX = 127.0
 # Quantized transmit tiles are (rows, LANE) lane rows; an int8 payload
 # tile needs 32 of them (the int8 sublane packing of one vreg).
 QUANT_BLOCK_ROWS = 32
+
+
+def mac_block_rows(rows: int, tiles: int) -> int:
+    """Block rows of a lane-dense f32 MAC launch over ``rows`` lane rows
+    that holds ``tiles`` (block_rows, LANE) f32 tiles in VMEM (each
+    operand and output tile twice, for the double-buffered pipeline,
+    plus the kernel's temporaries): as many rows as fit
+    ``MAC_VMEM_BYTES``, in whole sublane groups, or the whole axis when
+    it is shorter (a block that spans a dimension may have any
+    length). A launch whose tiles overflow the budget even at one
+    sublane group holds too many client rows, and is refused."""
+    br = MAC_VMEM_BYTES // (tiles * LANE * 4) // SUBLANE * SUBLANE
+    if br < SUBLANE:
+        raise ValueError(
+            f"an f32 MAC launch holding {tiles} tiles of ({SUBLANE}, "
+            f"{LANE}) f32 needs {tiles * SUBLANE * LANE * 4 >> 10} KiB of "
+            f"VMEM, over its {MAC_VMEM_BYTES >> 20} MiB budget: launch fewer "
+            "client rows at once (a smaller client_chunk)")
+    return min(rows, br)
+
+
+def channel_tiles(n: int) -> int:
+    """VMEM tiles of the f32 channel over ``n`` client rows: the
+    gradient, u, e and output tiles, double-buffered, the CMS
+    transform's temporaries and the row reduction's."""
+    return 2 * (n + 3) + CMS_TEMP_TILES + n - 1
+
+
+def stream_tiles(rc: int) -> int:
+    """VMEM tiles of the streamed transmit over ``rc`` client rows a
+    chunk: the gradient, carry and output tiles, double-buffered, and
+    the row reduction's temporaries."""
+    return 2 * (rc + 2) + rc - 1
+
+
+def _lane_rows(x: jax.Array, fill: float = 0.0) -> jax.Array:
+    """(d,) -> (ceil(d / LANE), LANE): a view for every slab (d % LANE
+    == 0 by the slab contract); other lengths are padded up to the next
+    LANE multiple with ``fill``. A stack (n, d) -> (n, ceil(d / LANE),
+    LANE) is assembled from its rows' views: a one-row stack is then a
+    view too, and a several-row one is copied row by row (the TPU
+    compiler takes minutes over one relayout reshape of a large stack)."""
+    if x.ndim == 2:
+        return jnp.stack([_lane_rows(row, fill) for row in x])
+    tail = -x.shape[0] % LANE
+    if tail:
+        x = jnp.pad(x, (0, tail), constant_values=fill)
+    return x.reshape(-1, LANE)
+
+
+def _lane_block_rows(rows: int, tiles: int, interpret: bool) -> int:
+    """The launch's block rows: ``mac_block_rows``, coarsened in
+    interpret mode."""
+    br = coarse_block(rows, mac_block_rows(rows, tiles), interpret,
+                      cap=INTERPRET_BLOCK_CAP // LANE)
+    return min(rows, br)
 
 
 def _residual_stats_row(xi: jax.Array, scale: float) -> jax.Array:
@@ -155,8 +235,9 @@ def _residual_stats_row(xi: jax.Array, scale: float) -> jax.Array:
     kernels: reduce this tile's interference residual ``r = scale * xi``
     to one (1, LANE) row ``[count, sum log|r|, sum log^2|r|, 0, ...]``
     over the nonzero entries (zero-mask == the padding/disabled-channel
-    fixed point). Runs on values already in VMEM/VREGs."""
-    r = jnp.abs(scale * xi.astype(jnp.float32)).reshape(-1)
+    fixed point; a tile's rows past the array's end are zeroed by the
+    caller). Runs on values already in VMEM/VREGs."""
+    r = jnp.abs(scale * xi.astype(jnp.float32))
     m = r > 0.0
     logr = jnp.where(m, jnp.log(jnp.maximum(r, jnp.finfo(jnp.float32).tiny)),
                      0.0)
@@ -187,14 +268,21 @@ def _sum_stats_rows(rows: jax.Array) -> jax.Array:
 
 
 def _ota_kernel(*refs, alpha: float, scale: float, n_clients: int,
-                stats: bool):
+                stats: bool, rows: Optional[int]):
     g_ref, h_ref, u_ref, e_ref, out_ref = refs[:5]
-    g = g_ref[...].astype(jnp.float32)              # (N, bc)
-    h = h_ref[...].astype(jnp.float32)              # (N, 1)
-    agg = jnp.sum(h * g, axis=0, keepdims=True) / n_clients   # (1, bc)
-    xi = cms_transform(u_ref[...], e_ref[...], alpha)         # (1, bc)
+    g = g_ref[...].astype(jnp.float32)              # (N, br, LANE)
+    h = h_ref[...].astype(jnp.float32)              # (N, 1, 1)
+    agg = jnp.sum(h * g, axis=0) / n_clients        # (br, LANE)
+    xi = cms_transform(u_ref[...], e_ref[...], alpha)         # (br, LANE)
     out_ref[...] = agg + scale * xi
     if stats:
+        if rows is not None:
+            # Ragged last block: its rows past the array's end hold
+            # unspecified values; zero them so they drop out.
+            br = xi.shape[0]
+            row = (pl.program_id(0) * br
+                   + jax.lax.broadcasted_iota(jnp.int32, xi.shape, 0))
+            xi = jnp.where(row < rows, xi, 0.0)
         refs[5][...] = _residual_stats_row(xi, scale)
 
 
@@ -202,7 +290,6 @@ def ota_channel_slab(grads: jax.Array, h: jax.Array, u: jax.Array,
                      e: jax.Array, *, alpha: float, scale: float,
                      n_total: int | None = None,
                      pilot_stats: bool = False,
-                     block_cols: int = DEFAULT_BLOCK_COLS,
                      interpret: Optional[bool] = None):
     """Fused f32 channel: grads (N, d) stacked client gradients, h (N,)
     fading draws, u (d,) uniform angles in (-pi/2, pi/2), e (d,) Exp(1)
@@ -222,34 +309,35 @@ def ota_channel_slab(grads: jax.Array, h: jax.Array, u: jax.Array,
     n, d = grads.shape
     if n_total is None:
         n_total = n
-    block_cols = coarse_block(d, block_cols, interpret)
-    d_pad = -(-d // block_cols) * block_cols
-    gp = jnp.pad(grads, ((0, 0), (0, d_pad - d)))
-    up = jnp.pad(u, (0, d_pad - d)).reshape(1, d_pad)
-    ep = jnp.pad(e, (0, d_pad - d), constant_values=1.0).reshape(1, d_pad)
-    h2 = h.reshape(n, 1).astype(jnp.float32)
-
-    grid = (d_pad // block_cols,)
-    out_specs = pl.BlockSpec((1, block_cols), lambda i: (0, i))
-    out_shape = jax.ShapeDtypeStruct((1, d_pad), jnp.float32)
+    g3 = _lane_rows(grads)
+    # A non-slab length pads u with 0 and e with 1: the tail then
+    # synthesizes exactly 0 interference and drops out of the stats.
+    u2, e2 = _lane_rows(u), _lane_rows(e, fill=1.0)
+    rows = g3.shape[1]
+    br = _lane_block_rows(rows, channel_tiles(n), interpret)
+    steps = pl.cdiv(rows, br)
+    blk = pl.BlockSpec((br, LANE), lambda i: (i, 0))
+    out_specs = blk
+    out_shape = jax.ShapeDtypeStruct((rows, LANE), jnp.float32)
     if pilot_stats:
         out_specs = [out_specs, _STATS_SPEC]
-        out_shape = [out_shape, _stats_shape(grid[0])]
+        out_shape = [out_shape, _stats_shape(steps)]
     outs = pl.pallas_call(
         functools.partial(_ota_kernel, alpha=alpha, scale=scale,
-                          n_clients=n_total, stats=pilot_stats),
-        grid=grid,
+                          n_clients=n_total, stats=pilot_stats,
+                          rows=rows if rows % br else None),
+        grid=(steps,),
         in_specs=[
-            pl.BlockSpec((n, block_cols), lambda i: (0, i)),
-            pl.BlockSpec((n, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, block_cols), lambda i: (0, i)),
-            pl.BlockSpec((1, block_cols), lambda i: (0, i)),
+            pl.BlockSpec((n, br, LANE), lambda i: (0, i, 0)),
+            pl.BlockSpec((n, 1, 1), lambda i: (0, 0, 0)),
+            blk,
+            blk,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
         name=obs.OTA_CHANNEL,
-    )(gp, h2, up, ep)
+    )(g3, h.reshape(n, 1, 1).astype(jnp.float32), u2, e2)
     if pilot_stats:
         return outs[0].reshape(-1)[:d], _sum_stats_rows(outs[1])
     return outs.reshape(-1)[:d]
@@ -266,8 +354,8 @@ def _tx_kernel(g_ref, h_ref, out_ref, *, n_clients: int):
 
 
 def _tx_stream_kernel(g_ref, h_ref, acc_ref, out_ref, *, n_clients: int):
-    """Streamed transmit: grid (col_blocks, row_chunks), col-outer. The
-    first row chunk seeds this column's output tile from the ``acc``
+    """Streamed transmit: grid (row_blocks, row_chunks), block-outer. The
+    first client chunk seeds this block's output tile from the ``acc``
     carry; every chunk then folds its faded partial in-place."""
     r = pl.program_id(1)
 
@@ -275,10 +363,9 @@ def _tx_stream_kernel(g_ref, h_ref, acc_ref, out_ref, *, n_clients: int):
     def _seed():
         out_ref[...] = acc_ref[...]
 
-    g = g_ref[...].astype(jnp.float32)              # (rc, bc)
-    h = h_ref[...].astype(jnp.float32)              # (rc, 1)
-    out_ref[...] = out_ref[...] + jnp.sum(h * g, axis=0,
-                                          keepdims=True) / n_clients
+    g = g_ref[...].astype(jnp.float32)              # (rc, br, LANE)
+    h = h_ref[...].astype(jnp.float32)              # (rc, 1, 1)
+    out_ref[...] = out_ref[...] + jnp.sum(h * g, axis=0) / n_clients
 
 
 def _tx_quant_kernel(*refs, n_clients: int, stochastic: bool, qmode: str,
@@ -413,7 +500,9 @@ def ota_transmit_slab(grads: jax.Array, h: jax.Array, *,
     kernel). Either argument selects the accumulating kernel; both are
     f32-only (``quantize=True`` raises — quantize the completed f32
     partial through a single-row launch instead, so every entry is
-    quantized exactly once).
+    quantized exactly once). The streamed launch tiles lane rows in
+    ``mac_block_rows`` of its shapes; ``block_cols`` tiles the other
+    launches.
     """
     interpret = resolve_interpret(interpret)
     n, d = grads.shape
@@ -432,24 +521,7 @@ def ota_transmit_slab(grads: jax.Array, h: jax.Array, *,
             "it with a single-row quantize=True launch")
     h2 = h.reshape(n, 1).astype(jnp.float32)
 
-    if not quantize:
-        d_pad = -(-d // block_cols) * block_cols
-        gp = jnp.pad(grads, ((0, 0), (0, d_pad - d)))
-        if not streamed:
-            out = pl.pallas_call(
-                functools.partial(_tx_kernel, n_clients=n_total),
-                grid=(d_pad // block_cols,),
-                in_specs=[
-                    pl.BlockSpec((n, block_cols), lambda i: (0, i)),
-                    pl.BlockSpec((n, 1), lambda i: (0, 0)),
-                ],
-                out_specs=pl.BlockSpec((1, block_cols), lambda i: (0, i)),
-                out_shape=jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
-                interpret=interpret,
-                name=obs.OTA_TRANSMIT,
-            )(gp, h2)
-            return out.reshape(-1)[:d]
-
+    if streamed:
         rc = n if row_chunk is None else min(row_chunk, n)
         if rc < 1:
             raise ValueError(f"row_chunk must be >= 1, got {row_chunk}")
@@ -458,26 +530,50 @@ def ota_transmit_slab(grads: jax.Array, h: jax.Array, *,
         if acc.shape != (d,):
             raise ValueError(f"acc must be the ({d},) running partial sum, "
                              f"got {acc.shape}")
+        g3 = _lane_rows(grads)
+        h3 = h2.reshape(n, 1, 1)
         # Zero rows contribute exactly 0 to the accumulation, so padding
         # the client axis up to a row-chunk multiple is value-neutral.
         n_pad = -(-n // rc) * rc
-        gp = jnp.pad(gp, ((0, n_pad - n), (0, 0)))
-        hp = jnp.pad(h2, ((0, n_pad - n), (0, 0)))
-        ap = jnp.pad(acc.astype(jnp.float32),
-                     (0, d_pad - d)).reshape(1, d_pad)
+        if n_pad != n:
+            g3 = jnp.pad(g3, ((0, n_pad - n), (0, 0), (0, 0)))
+            h3 = jnp.pad(h3, ((0, n_pad - n), (0, 0), (0, 0)))
+        rows = g3.shape[1]
+        br = _lane_block_rows(rows, stream_tiles(rc), interpret)
+        # The grid's last row block may be ragged: the math is per
+        # coordinate, and the rows past the end are never written back.
+        # The carry is updated in place (acc aliases the output).
         out = pl.pallas_call(
             functools.partial(_tx_stream_kernel, n_clients=n_total),
-            grid=(d_pad // block_cols, n_pad // rc),
+            grid=(pl.cdiv(rows, br), n_pad // rc),
             in_specs=[
-                pl.BlockSpec((rc, block_cols), lambda j, r_: (r_, j)),
-                pl.BlockSpec((rc, 1), lambda j, r_: (r_, 0)),
-                pl.BlockSpec((1, block_cols), lambda j, r_: (0, j)),
+                pl.BlockSpec((rc, br, LANE), lambda j, r_: (r_, j, 0)),
+                pl.BlockSpec((rc, 1, 1), lambda j, r_: (r_, 0, 0)),
+                pl.BlockSpec((br, LANE), lambda j, r_: (j, 0)),
             ],
-            out_specs=pl.BlockSpec((1, block_cols), lambda j, r_: (0, j)),
-            out_shape=jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
+            out_specs=pl.BlockSpec((br, LANE), lambda j, r_: (j, 0)),
+            out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
+            input_output_aliases={2: 0},
             interpret=interpret,
             name=obs.OTA_TRANSMIT_STREAM,
-        )(gp, hp, ap)
+        )(g3, h3, _lane_rows(acc.astype(jnp.float32)))
+        return out.reshape(-1)[:d]
+
+    if not quantize:
+        d_pad = -(-d // block_cols) * block_cols
+        gp = jnp.pad(grads, ((0, 0), (0, d_pad - d)))
+        out = pl.pallas_call(
+            functools.partial(_tx_kernel, n_clients=n_total),
+            grid=(d_pad // block_cols,),
+            in_specs=[
+                pl.BlockSpec((n, block_cols), lambda i: (0, i)),
+                pl.BlockSpec((n, 1), lambda i: (0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_cols), lambda i: (0, i)),
+            out_shape=jax.ShapeDtypeStruct((1, d_pad), jnp.float32),
+            interpret=interpret,
+            name=obs.OTA_TRANSMIT,
+        )(gp, h2)
         return out.reshape(-1)[:d]
 
     if d % LANE != 0:

@@ -69,6 +69,54 @@ def test_ota_channel_sweep(n_clients, d, alpha):
                                rtol=3e-4, atol=3e-4)
 
 
+@pytest.mark.parametrize("n_clients,rows", [(1, 13), (4, 21)])
+def test_ota_channel_ragged_block_stats(n_clients, rows, monkeypatch):
+    """The f32 channel on a ragged last block of 8-row lane tiles: the
+    output matches the oracle, and the pilot statistics equal the
+    oracle's, so the block's rows past the end add nothing. The
+    interpreter fills those rows with NaN, which the stats' zero mask
+    drops by itself; here it fills them with 1.0, finite garbage like
+    what the chip's VMEM holds there."""
+    from jax._src.pallas import primitives
+    import repro.kernels.ota_channel as oc
+    from repro.core.channel import cms_inputs
+    monkeypatch.setattr(oc, "coarse_block", lambda n_, b, i, cap=None: b)
+    monkeypatch.setattr(oc, "mac_block_rows", lambda rows, tiles: 8)
+    fill = primitives.uninitialized_value
+    monkeypatch.setattr(
+        primitives, "uninitialized_value",
+        lambda shape, dtype: (jnp.full(shape, 1.0, dtype)
+                              if jnp.issubdtype(dtype, jnp.floating)
+                              else fill(shape, dtype)))
+    d = rows * 128
+    G = jax.random.normal(jax.random.key(rows), (n_clients, d))
+    h = jax.random.uniform(jax.random.key(1), (n_clients,), minval=0.5,
+                           maxval=1.5)
+    u, e = cms_inputs(jax.random.key(2), (d,))
+    out, st = oc.ota_channel_slab(G, h, u, e, alpha=1.5, scale=0.3,
+                                  pilot_stats=True, interpret=True)
+    out_r, st_r = ota_channel_ref(G, h, u, e, alpha=1.5, scale=0.3,
+                                  pilot_stats=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(out_r),
+                               rtol=3e-4, atol=3e-4)
+    assert float(st[0]) == float(st_r[0]) == d
+    np.testing.assert_allclose(np.asarray(st), np.asarray(st_r), rtol=1e-5)
+
+
+@pytest.mark.parametrize("launch,largest", [("channel", 1020),
+                                            ("stream", 1023)])
+def test_mac_block_rows_refuses_vmem_overflow(launch, largest):
+    """The most client rows an f32 MAC launch takes: one sublane group
+    of every tile fits the VMEM budget; one row more is refused with a
+    clear error rather than left to fail in the TPU compiler."""
+    import repro.kernels.ota_channel as oc
+    tiles = getattr(oc, f"{launch}_tiles")
+    assert oc.mac_block_rows(10_000, tiles(largest)) == 8
+    assert oc.mac_block_rows(5, tiles(largest)) == 5
+    with pytest.raises(ValueError, match="VMEM"):
+        oc.mac_block_rows(10_000, tiles(largest + 1))
+
+
 @pytest.mark.parametrize("mode", ["amsgrad", "yogi", "momentum", "sgd"])
 @pytest.mark.parametrize("n", [1, 127, 1000, 70_000])
 def test_adaptive_update_extended_modes(mode, n):
@@ -255,7 +303,7 @@ def test_coarse_block_policy():
     assert coarse_block(10_000, 256, True, cap=1000) == 768
 
 
-def test_coarse_block_bitwise_invariant():
+def test_coarse_block_bitwise_invariant(monkeypatch):
     """The coarsened interpret launch is BITWISE identical to the
     fixed-tile launch on the channel output — per-column math and the
     per-128-block scales are invariant to the d-axis tiling (the
@@ -264,17 +312,17 @@ def test_coarse_block_bitwise_invariant():
     the grid: those are held to ~1 ULP instead."""
     import repro.kernels.ota_channel as oc
 
-    n, d = 4, 1000   # 4 x 256-tiles when fixed, 1 tile when coarsened
+    n, d = 4, 1000   # 4 x 2-row tiles when fixed, 1 tile when coarsened
     ks = jax.random.split(jax.random.key(42), 4)
     g = jax.random.normal(ks[0], (n, d))
     h = jnp.abs(jax.random.normal(ks[1], (n,))) + 0.1
     u = jax.random.uniform(ks[2], (d,), minval=-1.5, maxval=1.5)
     e = jnp.abs(jax.random.normal(ks[3], (d,))) + 0.01
+    monkeypatch.setattr(oc, "mac_block_rows", lambda rows, tiles: 2)
 
     def launch():
         return oc.ota_channel_slab(g, h, u, e, alpha=1.5, scale=0.1,
-                                   pilot_stats=True, block_cols=256,
-                                   interpret=True)
+                                   pilot_stats=True, interpret=True)
 
     coarse_out, coarse_stats = launch()
     orig = oc.coarse_block

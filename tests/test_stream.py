@@ -20,6 +20,8 @@ The acceptance contracts:
   see the COMPLETED partial).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -397,6 +399,44 @@ def test_transmit_acc_chaining_matches_ref():
     ref = ota_transmit_ref(g, h, n_total=n, row_chunk=5)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(full),
                                rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("rc", [1, 3])
+@pytest.mark.parametrize("rows", [13, 21])
+def test_transmit_stream_ragged_block_bitwise(rc, rows, monkeypatch):
+    """The streamed transmit tiles (rows, 128) lane rows in blocks of 8.
+    With a ragged last block, client chunks of rc rows (3 pads 4 rows
+    to 6) and the carry chained over 4 launches, every entry is bitwise
+    the one-block launch's, and, with one client a chunk, the oracle's.
+    Several clients a chunk reduce in the kernel's own f32 order, as
+    they did in the (1, 512)-column tiling: there the oracle is held to
+    1e-6."""
+    import repro.kernels.ota_channel as oc
+    from repro.kernels.ref import ota_transmit_ref
+    d, n = rows * 128, 4
+    g = jax.random.normal(jax.random.key(rows), (4, n, d))
+    h = jax.random.uniform(jax.random.key(rc), (4, n), minval=0.5,
+                           maxval=1.5)
+    acc0 = jax.random.normal(jax.random.key(7), (d,))
+
+    def chain(launch):
+        acc = acc0
+        for k in range(4):
+            acc = launch(g[k], h[k], n_total=4 * n, acc=acc, row_chunk=rc)
+        return np.asarray(acc)
+
+    one_block = chain(functools.partial(oc.ota_transmit_slab,
+                                        interpret=True))
+    # 8-row tiles, kept under interpret (no coarsening to one block).
+    monkeypatch.setattr(oc, "mac_block_rows", lambda rows, tiles: 8)
+    monkeypatch.setattr(oc, "coarse_block", lambda n_, b, i, cap=None: b)
+    tiled = chain(functools.partial(oc.ota_transmit_slab, interpret=True))
+    ref = chain(ota_transmit_ref)
+    np.testing.assert_array_equal(tiled, one_block)
+    if rc == 1:
+        np.testing.assert_array_equal(tiled, ref)
+    else:
+        np.testing.assert_allclose(tiled, ref, rtol=1e-6, atol=1e-6)
 
 
 def test_transmit_acc_refuses_quantize():

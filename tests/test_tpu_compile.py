@@ -11,20 +11,30 @@ The topology is described inside a module fixture (never at import):
 only one process at a time may load the TPU library, and the test
 workers all import this file. Where it cannot be described, the
 fixture skips the file's tests.
+
+The case ``streamed_mac_ragged_rows`` compiles a streamed round's MAC
+(per-client packing, chunk launches folding into a scan carry, then the
+f32 channel) at that slab length, whose lane rows are a multiple of
+neither 8 nor the block rows, and reads the compiled text: no
+slab-sized pad, slice or relayout feeds or reads either kernel, and
+the carry is updated in place.
 """
 
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import make_slab_spec
+from repro.core import make_slab_spec, stack_to_slab
 from repro.kernels.adaptive_update import adaptive_update_slab
-from repro.kernels.ota_channel import (LANE, ota_channel_slab,
-                                       ota_receive_slab, ota_transmit_slab)
+from repro.kernels.ota_channel import (LANE, channel_tiles,
+                                       mac_block_rows, ota_channel_slab,
+                                       ota_receive_slab, ota_transmit_slab,
+                                       stream_tiles)
 from repro.launch.train import preset_config
 from repro.models.model import build_model
 
@@ -64,11 +74,98 @@ def slab_len():
     return make_slab_spec(shapes).padded
 
 
+def _leaves(d):
+    """Three bf16 model leaves whose slab is d long."""
+    return {"w": (1000, 777), "v": (3001,), "b": (d - 780_001,)}
+
+
+def _streamed_mac(w, v, b, h, u, e):
+    """A streamed round's MAC: each client's gradient leaves (fusions,
+    as in the round) are packed into its slab, which folds into the scan
+    carry through the streamed transmit and into the clean sum; the
+    completed partial crosses the f32 channel as one row."""
+    leaves = {"w": w, "v": v, "b": b}
+    spec = make_slab_spec({k: jax.ShapeDtypeStruct(x.shape[1:], x.dtype)
+                           for k, x in leaves.items()})
+
+    def body(carry, c):
+        acc, clean = carry
+        grads = {k: jnp.sin(jax.lax.dynamic_index_in_dim(x, c))
+                 for k, x in leaves.items()}
+        g_stack = stack_to_slab(spec, grads)
+        h_c = jax.lax.dynamic_index_in_dim(h, c)
+        acc = ota_transmit_slab(g_stack, h_c, acc=acc, n_total=ROWS,
+                                interpret=False)
+        return (acc, clean + jnp.sum(g_stack, axis=0)), None
+
+    zeros = jnp.zeros((spec.padded,), F32)
+    (acc, clean), _ = jax.lax.scan(body, (zeros, zeros), jnp.arange(ROWS))
+    return ota_channel_slab(acc[None], jnp.ones((1,), F32), u, e,
+                            **_CH), clean
+
+
+_HLO_LINE = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\S+) ([\w-]+)\((.*?)\)")
+
+
+def _hlo_instructions(text):
+    """name -> (opcode, element count, operand names) of every
+    instruction with an array result in compiled HLO text."""
+    out = {}
+    for line in text.splitlines():
+        m = _HLO_LINE.match(line)
+        if not m:
+            continue
+        dims = re.match(r"\w+\[([\d,]*)\]", m.group(2))
+        if dims is None:
+            continue
+        size = 1
+        for x in filter(None, dims.group(1).split(",")):
+            size *= int(x)
+        out[m.group(1)] = (m.group(3), size, re.findall(r"%([\w.-]+)",
+                                                        m.group(4)))
+    return out
+
+
+def _assert_mac_in_place(text, d):
+    """Both MAC kernels are custom calls; no slab-sized pad, slice or
+    relayout (a copy, or a reduce over a unit axis) feeds or reads
+    either (through bitcasts); the streamed transmit's accumulator
+    (operand 2) is aliased to its output."""
+    ins = _hlo_instructions(text)
+
+    def source(name):
+        while ins[name][0] == "bitcast":
+            name = ins[name][2][0]
+        return name
+
+    def readers(name):
+        for other, (op, _, args) in ins.items():
+            if name in args:
+                yield from (readers(other) if op == "bitcast" else [other])
+
+    for kernel in ("ota_transmit_stream", "ota_channel"):
+        calls = [k for k in ins if re.fullmatch(kernel + r"(\.\d+)?", k)]
+        assert calls, f"no {kernel} call in the compiled program"
+        for call in calls:
+            assert ins[call][0] == "custom-call"
+            near = ([source(a) for a in ins[call][2] if a in ins]
+                    + list(readers(call)))
+            bad = [k for k in near
+                   if ins[k][0] in ("pad", "slice", "copy", "reduce")
+                   and ins[k][1] >= d]
+            assert not bad, f"slab-sized {bad} around {call}"
+    stream = [line for line in text.splitlines()
+              if re.search(r"%ota_transmit_stream(\.\d+)? = ", line)]
+    assert stream and all("output_to_operand_aliasing={{}: (2, {})}" in line
+                          for line in stream)
+
+
 # name -> (kernel call, operand shapes as (shape, dtype) given d)
 _CH = dict(alpha=1.5, scale=0.05, interpret=False)
 _AD = dict(lr=0.02, beta1=0.9, beta2=0.3, eps=1e-8, mode="adam",
            interpret=False)
-F32, I8, U32, I32 = jnp.float32, jnp.int8, jnp.uint32, jnp.int32
+F32, BF16, I8, U32, I32 = (jnp.float32, jnp.bfloat16, jnp.int8, jnp.uint32,
+                          jnp.int32)
 CASES = {
     "channel": (
         lambda g, h, u, e: ota_channel_slab(g, h, u, e, **_CH),
@@ -121,6 +218,10 @@ CASES = {
         lambda g, m, v, w: adaptive_update_slab(g, m, v, w, alpha=1.5,
                                                 **_AD),
         lambda d: [((d,), F32)] * 4),
+    "streamed_mac_ragged_rows": (
+        _streamed_mac,
+        lambda d: [((ROWS,) + shape, BF16) for shape in _leaves(d).values()]
+        + [((ROWS,), F32), ((d,), F32), ((d,), F32)]),
     "adam_traced_alpha": (
         lambda a, g, m, v, w: adaptive_update_slab(g, m, v, w, alpha=a,
                                                    **_AD),
@@ -133,5 +234,12 @@ def test_kernel_compiles_for_v5e(name, one_chip, slab_len):
     fn, operands = CASES[name]
     args = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
             for shape, dt in operands(slab_len)]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    if name == "streamed_mac_ragged_rows":
+        # The slab's lane rows are a multiple of neither 8 nor the
+        # block rows of either launch: both grids end on a ragged block.
+        rows = slab_len // LANE
+        assert rows % 8 and rows % mac_block_rows(rows, stream_tiles(1))
+        assert rows % mac_block_rows(rows, channel_tiles(1))
+        _assert_mac_in_place(text, slab_len)

@@ -17,7 +17,10 @@ control seed also:
 * ``control_model``: the same with the model alone one step down, the
   aggregate and server state at their float32;
 * ``half``: the reference with half of every client's batch left out,
-  the mean taken over the rest (a planted fault).
+  the mean taken over the rest (a planted fault);
+* ``exchange`` (cells on several chips): the program itself with the
+  exchange between chips left out, each chip keeping its own slice of
+  its own clients' sums (``exchange_left_out``, a planted fault).
 
 A step that returns its state unchanged reads 1 on ``dw_gap`` by
 construction and needs no run. One JSON line per seed and kind.
@@ -26,8 +29,10 @@ construction and needs no run. One JSON line per seed and kind.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import gc
 import json
+import math
 import os
 import sys
 import time
@@ -37,7 +42,29 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
 
 # (model, state) precision of each control
 CONTROLS = {"control": ("fp8", "bf16"), "control_model": ("fp8", "f32")}
-KINDS = (*CONTROLS, "half")
+KINDS = (*CONTROLS, "half", "exchange")
+
+
+@contextlib.contextmanager
+def exchange_left_out():
+    """Plant a fault in the sharded round built inside the block: its
+    reduce-scatter (``repro.core.shard.psum_scatter_slab``) hands each
+    chip its own slice of its own partial sums, unsummed over chips."""
+    import jax
+    from repro.core import shard
+    from repro.core.ota import linear_shard_index
+    real = shard.psum_scatter_slab
+
+    def local(x, axes, dim=0):
+        size = x.shape[dim] // math.prod(jax.lax.axis_size(a) for a in axes)
+        return jax.lax.dynamic_slice_in_dim(
+            x, linear_shard_index(axes) * size, size, axis=dim)
+
+    shard.psum_scatter_slab = local
+    try:
+        yield
+    finally:
+        shard.psum_scatter_slab = real
 
 
 def main(argv=None) -> int:
@@ -77,10 +104,30 @@ def readings(workload, seeds, cseeds, bench_file=None, data_dir=None,
     cell = resolve(workload, bench_file, data_dir or BENCH_DIR)
     if cache:
         enable_cache()
-    check_devices(cell.chips, need_tpu)
+    devs = check_devices(cell.chips, need_tpu)[:cell.chips]
     layout = cell.reference.layout(cell.conf)
     program = prog.Program(cell.conf, cell.mix, cell.chips, layout)
-    call = None
+    programs = {}          # kind -> [program, compiled call]
+
+    def program_readings(kind, init_key, key_fn, batch_fn):
+        with (exchange_left_out() if kind == "exchange"
+              else contextlib.nullcontext()):
+            if kind not in programs:
+                programs[kind] = [program if kind == "program" else
+                                  prog.Program(cell.conf, cell.mix,
+                                               cell.chips, layout), None]
+            p, call = programs[kind]
+            state = p.init_state(init_key)
+            if call is None:
+                call = programs[kind][1] = prog.compile_round_program(
+                    p, state, key_fn, batch_fn)[0]
+        loop = prog.RoundLoop(p, state, key_fn, batch_fn, call)
+        del state
+        read = prog.setup_rounds(loop, p, init_key)
+        loop.state = None
+        del loop
+        gc.collect()
+        return read
 
     for seed in seeds:
         t0 = time.perf_counter()
@@ -90,16 +137,10 @@ def readings(workload, seeds, cseeds, bench_file=None, data_dir=None,
         def batch_fn(t, _key):
             return {"tokens": jnp.asarray(traffic.batch_of(t))}
 
-        state = program.init_state(init_key)
-        if call is None:
-            call, _ = prog.compile_round_program(program, state, key_fn,
-                                                 batch_fn)
-        loop = prog.RoundLoop(program, state, key_fn, batch_fn, call)
-        del state
-        read = prog.setup_rounds(loop, program, init_key)
-        loop.state = None
-        del loop
-        gc.collect()
+        read = program_readings("program", init_key, key_fn, batch_fn)
+        faulty = None
+        if seed in cseeds and "exchange" in kinds and cell.chips > 1:
+            faulty = program_readings("exchange", init_key, key_fn, batch_fn)
         t1 = time.perf_counter()
         batches = [traffic.batch_of(t) for t in range(prog.SETUP_ROUNDS)]
         keys = [key_fn(t) for t in range(prog.SETUP_ROUNDS)]
@@ -108,7 +149,8 @@ def readings(workload, seeds, cseeds, bench_file=None, data_dir=None,
         def ref_run(pr=Precision(), drop_half=False):
             return reference_rounds(
                 lambda p, tok, pr: cell.reference.loss(cell.conf, p, tok, pr),
-                params0, batches, keys, cell.mix, pr=pr, drop_half=drop_half)
+                params0, batches, keys, cell.mix, pr=pr, drop_half=drop_half,
+                devices=devs)
 
         ref = ref_run()
         t2 = time.perf_counter()
@@ -117,6 +159,12 @@ def readings(workload, seeds, cseeds, bench_file=None, data_dir=None,
                "ref_loss": ref["loss"], "program_s": t1 - t0,
                "reference_s": t2 - t1})
         for kind in kinds if seed in cseeds else ():
+            if kind == "exchange":
+                if faulty is not None:
+                    yield ({"seed": seed, "kind": kind,
+                            **compare.numbers(faulty, ref),
+                            "loss": faulty["loss"]})
+                continue
             other = (ref_run(drop_half=True) if kind == "half" else
                      ref_run(Precision(*CONTROLS[kind])))
             yield ({"seed": seed, "kind": kind, **compare.numbers(other, ref),

@@ -72,6 +72,14 @@ def update_bytes(layout: dict) -> int:
     return 3 * 2 * 4 * param_count(layout)
 
 
+def exchange_bytes(padded: int, chips: int, slabs: int = 3) -> float:
+    """Least bytes one chip sends in a round's exchange over ``chips``
+    chips: (P - 1) / P of every float32 slab of ``padded`` entries that is
+    all-gathered or reduce-scattered. The sharded round gathers the model
+    broadcast and scatters the faded partial and the clean sum: 3 slabs."""
+    return (chips - 1) / chips * 4 * padded * slabs
+
+
 def least_seconds(nbytes: float, ops: float, peak: dict) -> float:
     """The larger of the byte bound and the operation bound."""
     return max(nbytes / peak["hbm_bytes_s"], ops / peak["bf16_flops"])

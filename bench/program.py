@@ -1,10 +1,14 @@
 """The system under test, driven as its users drive it.
 
 Builds the program's round runner (``repro.core.fl.make_slab_round_runner``,
-donated state, backend ``pallas`` on one chip), its state from
-``init_train_state`` in one jitted program, and
-runs rounds through the program's own round loop, ``run_rounds_slab``,
-one scan chunk per call. Everything the benchmark reads from the program
+donated state), its state from ``init_train_state`` in one jitted
+program, and runs rounds through the program's own round loop,
+``run_rounds_slab``, one scan chunk per call. The number of chips alone
+picks the engine, as ``repro.launch.train`` does: backend ``pallas`` on
+one chip; on more, backend ``pallas_sharded`` over a client mesh of that
+many chips, with the state slabs split over it
+(``repro.core.shard.place_slab_state``) and the client batches over its
+client axis. Everything the benchmark reads from the program
 passes through here: the compiled round program's memory analysis, the
 round metrics, and the state slabs after rounds 1 and 3.
 """
@@ -23,6 +27,8 @@ from repro.configs import get_config
 from repro.core import (AdaptiveConfig, FLConfig, OTAChannelConfig,
                         UplinkConfig, init_train_state, make_slab_round_runner,
                         make_slab_spec, run_rounds_slab)
+from repro.core.shard import place_slab_state
+from repro.launch.mesh import make_client_mesh
 from repro.models.model import ModelConfig, build_model
 
 from bench import mix as mixes
@@ -64,10 +70,14 @@ class Program:
     """One cell's program: model, configs, runner and slab layout."""
 
     def __init__(self, conf: dict, mix: dict, chips: int, ref_layout: dict,
-                 loss_fn: Optional[Callable] = None):
-        if chips != 1:
-            raise ValueError("the benchmark drives the one-chip engine only; "
-                             "no cell runs the sharded one yet")
+                 loss_fn: Optional[Callable] = None, mesh=None):
+        # One chip: no mesh. More: ``mesh`` where given (bench/rehearse.py
+        # gives one over described chips), else a client mesh over jax's
+        # devices, which raises where it sees fewer than the cell asks for.
+        if chips > 1 and mesh is None:
+            mesh = make_client_mesh((chips,))
+        self.mesh = mesh if chips > 1 else None
+        backend = "pallas" if self.mesh is None else "pallas_sharded"
         self.cfg = model_config(conf)
         self.model = build_model(self.cfg)
         mixes.check(mix)
@@ -76,14 +86,15 @@ class Program:
         self.rounds_per_chunk = int(eng["rounds_per_chunk"])
         chan = {k: v for k, v in ch.items() if k != "uplink"}
         self.channel = OTAChannelConfig(
-            backend="pallas", uplink=UplinkConfig(**ch["uplink"]), **chan)
-        self.server = AdaptiveConfig(backend="pallas", **sv)
+            backend=backend, uplink=UplinkConfig(**ch["uplink"]), **chan)
+        self.server = AdaptiveConfig(backend=backend, **sv)
         self.fl = FLConfig(n_clients=int(fed["clients_per_round"]),
                            client_chunk=int(eng["client_chunk"]))
         model = self.model
         loss = loss_fn or (lambda p, b: model.loss_fn(p, b))
         self.run_chunk = make_slab_round_runner(
-            loss, self.channel, self.server, self.fl, donate=True)
+            loss, self.channel, self.server, self.fl, mesh=self.mesh,
+            donate=True)
         shapes = jax.eval_shape(model.init, jax.random.key(0))
         got, want = layout_of(shapes), {
             "/".join(p): (tuple(s), jnp.dtype(d).name)
@@ -93,13 +104,16 @@ class Program:
             raise ValueError(f"the program's parameter layout differs from "
                              f"the reference's: {diff}")
         self.ref_layout = ref_layout
-        self.spec = make_slab_spec(shapes)
+        self.spec = make_slab_spec(shapes, shards=chips)
 
     def init_state(self, init_key):
-        """The resident state, drawn and packed in one jitted program."""
+        """The resident state, drawn and packed in one jitted program;
+        on a mesh, split over it as the round runner returns it."""
         spec, ad, layout = self.spec, self.server, self.ref_layout
-        return jax.jit(lambda k: init_train_state(
+        state = jax.jit(lambda k: init_train_state(
             ad, init_params(layout, k), spec=spec))(init_key)
+        return state if self.mesh is None else place_slab_state(state,
+                                                                self.mesh)
 
     def leaf_norms(self):
         """Jitted (padded,) slab -> per-leaf L2 norms, in leaf order."""

@@ -14,7 +14,9 @@ gradient per client):
 
 Clients read the weights in their storage dtype (bfloat16 matrices), and
 the update starts from that rounded copy, as the configuration states.
-Everything runs leaf by leaf, client by client, in float32.
+Everything runs leaf by leaf, client by client, in float32. Given several
+devices, client n computes on device n mod D, each device sums its own
+clients, and the first device adds the D partial sums.
 """
 
 from __future__ import annotations
@@ -61,7 +63,8 @@ def _norms(leaves) -> np.ndarray:
 
 def reference_rounds(loss: Callable, params0: dict, batches: Sequence,
                      keys: Sequence, mix: dict,
-                     pr: Precision = F32, drop_half: bool = False) -> dict:
+                     pr: Precision = F32, drop_half: bool = False,
+                     devices: Sequence = ()) -> dict:
     """Run ``len(batches)`` rounds; return per-round loss and clean
     aggregate norm, per-leaf norms of the first round's g_t and of the
     reference's clean first gradient, and per-leaf norms of w - w_0.
@@ -69,6 +72,8 @@ def reference_rounds(loss: Callable, params0: dict, batches: Sequence,
     ``loss(params, tokens, pr)`` is one client's loss. ``batches[t]`` is
     round t's (N, B, S) int32 array. ``drop_half`` is a planted fault:
     every client computes on the first half of its rows only.
+    ``devices`` spreads the clients over several devices (default: jax's
+    first device alone).
     """
     ch, sv = mixes.check(mix)["channel"], mix["server"]
     alpha, scale = float(ch["alpha"]), float(ch["xi_scale"])
@@ -84,6 +89,8 @@ def reference_rounds(loss: Callable, params0: dict, batches: Sequence,
     delta = [jnp.zeros_like(x) for x in w]
     nu = [jnp.zeros_like(x) for x in w]
     treedef = jax.tree.structure(params0)
+    devs = list(devices) or [jax.devices()[0]]
+    spread = len(devs) > 1
 
     with jax.default_matmul_precision("highest"):
         vg = jax.jit(jax.value_and_grad(
@@ -112,6 +119,16 @@ def reference_rounds(loss: Callable, params0: dict, batches: Sequence,
                 out.append((wi, di, ni))
             return [o[0] for o in out], [o[1] for o in out], [o[2] for o in out]
 
+        @jax.jit
+        def merge(parts):
+            return [sum(p[i] for p in parts) for i in range(len(parts[0]))]
+
+        def on_first(parts):
+            """The devices' partial sums added on the first device."""
+            if not spread:
+                return parts[0]
+            return merge([jax.device_put(p, devs[0]) for p in parts])
+
         out = {"loss": [], "clean_norm": []}
         for t, (tok_all, key) in enumerate(zip(batches, keys)):
             n = tok_all.shape[0]
@@ -119,19 +136,32 @@ def reference_rounds(loss: Callable, params0: dict, batches: Sequence,
             h = np.asarray(rayleigh(kh, n, mu_c))
             client_w = [x.astype(dt).astype(jnp.float32)
                         for x, dt in zip(w, dtypes)]
-            acc = [jnp.zeros_like(x) for x in w]
-            clean = [jnp.zeros_like(x) for x in w]
-            loss_sum = 0.0
+            on = ([jax.device_put(client_w, d) for d in devs] if spread
+                  else [client_w])
+            del client_w
+            acc = [[jnp.zeros_like(x) for x in cw] for cw in on]
+            clean = [[jnp.zeros_like(x) for x in cw] for cw in on]
+            losses = []
             for c in range(n):
-                tok = jnp.asarray(tok_all[c])
+                k = c % len(devs)
+                if c >= len(devs):
+                    # one client in flight a device: its gradient and
+                    # activations are freed before the next is started
+                    jax.block_until_ready(clean[k])
+                tok = (jax.device_put(tok_all[c], devs[k]) if spread
+                       else jnp.asarray(tok_all[c]))
                 if drop_half:
                     tok = tok[:max(1, tok.shape[0] // 2)]
-                lc, g = vg(client_w, tok)
-                loss_sum += float(lc)
-                acc = fold(acc, g, float(h[c]) / n)
-                clean = fold(clean, g, 1.0 / n)
+                lc, g = vg(on[k], tok)
+                losses.append(lc)
+                acc[k] = fold(acc[k], g, float(h[c]) / n)
+                clean[k] = fold(clean[k], g, 1.0 / n)
                 del g
-            del client_w
+            del on
+            loss_sum = 0.0
+            for lc in losses:
+                loss_sum += float(lc)
+            acc, clean = on_first(acc), on_first(clean)
             g_t = noisy(acc, kx)
             del acc
             out["loss"].append(loss_sum / n)

@@ -147,6 +147,8 @@ def facts_of(cell, program, rounds: int, kind: str) -> dict:
             "mac_bytes": counts.mac_bytes(layout, n),
             "mac_ops": counts.mac_ops(layout),
             "update_bytes": counts.update_bytes(layout),
+            "comm_bytes": counts.exchange_bytes(program.spec.padded,
+                                                cell.chips),
             "slab": program.spec.padded}
 
 
@@ -226,7 +228,8 @@ def run(args, bench_file=None, data_dir=None, need_tpu=True, cache=True,
     ref = reference_rounds(
         lambda p, tok, pr: cell.reference.loss(cell.conf, p, tok, pr),
         params0, [traffic.batch_of(t) for t in range(prog.SETUP_ROUNDS)],
-        [key_fn(t) for t in range(prog.SETUP_ROUNDS)], cell.mix)
+        [key_fn(t) for t in range(prog.SETUP_ROUNDS)], cell.mix,
+        devices=devs[:cell.chips])
     del params0
     ref_s = time.perf_counter() - t_ref
     nums = compare.numbers(read, ref)

@@ -8,7 +8,8 @@ For every number compared (bench/compare.py):
 * lower: the largest reading of the sound program over its seeds;
 * upper: the smallest reading of each control (``control``: model and
   state one step down; ``control_model``: the model alone), where it is
-  three times the lower or more; of the half-batch fault's, where ten
+  three times the lower or more; of each planted fault's (half of the
+  batch left out; on several chips, the exchange left out), where ten
   times or more; and 1.0 for ``dw_gap`` (a state left unchanged reads 1
   there), where three times or more; the least of these;
 * limit: ``lower * (upper / lower) ** 0.6``, two significant digits: above
@@ -29,6 +30,7 @@ import sys
 
 NUMBERS = ("loss_gap", "gnorm_gap", "grad1_gap", "dw_gap")
 CONTROLS = ("control", "control_model")
+FAULTS = {"half": "half-batch fault", "exchange": "exchange left out"}
 SHARE = 0.6
 
 
@@ -50,14 +52,19 @@ def limits_from(records: list) -> dict:
                 ctl[kind] = min(got)
                 if min(got) >= 3 * lower:
                     cands.append((kind, min(got)))
-        half = [r[n] for r in by.get("half", [])]
-        if half and min(half) >= 10 * lower:
-            cands.append(("half-batch fault", min(half)))
+        faults = {}
+        for kind, label in FAULTS.items():
+            got = [r[n] for r in by.get(kind, [])]
+            faults[kind] = min(got) if got else None
+            if got and min(got) >= 10 * lower:
+                cands.append((label, min(got)))
         if n == "dw_gap" and 1.0 >= 3 * lower:
             cands.append(("state left unchanged", 1.0))
         entry = {"lower": lower, "seeds": len(prog),
                  **{k: ctl.get(k) for k in CONTROLS},
-                 "half_batch": min(half) if half else None}
+                 "half_batch": faults["half"]}
+        if faults["exchange"] is not None:
+            entry["exchange_left_out"] = faults["exchange"]
         if cands:
             src, upper = min(cands, key=lambda c: c[1])
             lim = float(f"{lower * (upper / lower) ** SHARE:.2g}")

@@ -93,7 +93,7 @@ def test_new_cell_needs_only_files(tmp_path):
     assert cell.conf["name"] == "tiny-mla"
 
 
-@pytest.mark.parametrize("traffic", ["silo8", "sonwp50"])
+@pytest.mark.parametrize("traffic", ["silo8", "sonwp50", "silo32"])
 def test_traffic_is_a_function_of_the_seed(traffic):
     mix = json.load(open(os.path.join(ROOT, "bench", "traffic",
                                       traffic + ".json")))
@@ -309,9 +309,11 @@ def test_mix_the_reference_does_not_implement_is_refused(block, key, value):
 
 
 def test_program_refuses_a_mesh():
-    """The harness drives the one-chip engine; a cell on more chips
-    fails before anything is built."""
+    """A cell on more chips than jax sees fails before anything is
+    built."""
+    import jax
     from bench.program import Program
     cell = resolve("hymba-l2.silo8")
-    with pytest.raises(ValueError, match="one-chip"):
-        Program(cell.conf, cell.mix, 4, cell.reference.layout(cell.conf))
+    chips = len(jax.devices()) + 1
+    with pytest.raises(ValueError, match=f"needs {chips} devices"):
+        Program(cell.conf, cell.mix, chips, cell.reference.layout(cell.conf))

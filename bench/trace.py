@@ -117,6 +117,18 @@ def is_pallas(op_text: str) -> bool:
     return PALLAS in op_text
 
 
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+               "collective-permute")
+_OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)(?:\(|$)")
+
+
+def is_collective(op_text: str) -> bool:
+    """An op that moves data between chips: by its HLO opcode (with its
+    ``-start`` and ``-done`` halves where the compiler made it async)."""
+    m = _OPCODE.search(op_text.split(" = ", 1)[-1])
+    return bool(m) and m.group(1).startswith(COLLECTIVES)
+
+
 def is_update(op_text: str) -> bool:
     """The server update's Pallas call: the one that returns a tuple of
     (rows, 128) state slabs."""
@@ -167,19 +179,31 @@ class Round:
 class View:
     """A loaded trace with the cell's facts, handed to every reader.
 
+    ``planes`` are all the chips the trace holds; ``devices`` the ones the
+    readers read. On one chip they are the same. On several, the readers
+    read one chip, the one whose round-program executions take the most
+    device time over the window, since every round waits for it; only
+    ``mean_busy_s`` (the result's ``busy_s``, and ``idle_share``)
+    averages over all of them.
+
     ``facts`` holds: ``rounds`` (rounds traced), ``chips``, ``peak`` (the
     chip's row of bench/counts.PEAKS), ``flops_round`` (model FLOPs of a
     round's clients), ``mac_bytes``, ``mac_ops``, ``update_bytes``,
     ``slab`` (entries of the state slab), ``mac_calls`` (MAC Pallas calls
-    a round of the known shape makes).
+    a round of the known shape makes), ``comm_bytes`` (the least bytes a
+    chip sends in a round's exchange; 0 on one chip).
     """
 
     def __init__(self, trace: dict, facts: dict):
         self.trace, self.facts = trace, facts
-        self.devices = sorted(trace["devices"])
+        self.planes = sorted(trace["devices"])
         self.t0, self.t1 = trace["window_ns"]
         self._leaves, self._rounds = {}, {}
         self._told = False
+        self.devices = self.planes
+        if len(self.planes) > 1:
+            self.devices = [max(self.planes, key=lambda d: sum(
+                b - a for a, b in self.main_module(d)))]
 
     @property
     def window_s(self) -> float:
@@ -227,7 +251,7 @@ class View:
         return union_ns(clipped) * 1e-9
 
     def mean_busy_s(self) -> float:
-        return sum(self.busy_s(d) for d in self.devices) / len(self.devices)
+        return sum(self.busy_s(d) for d in self.planes) / len(self.planes)
 
     def per_round_ms(self, pick) -> float | None:
         """Mean over chips of the summed duration of ``pick(round)``'s
@@ -246,6 +270,26 @@ class View:
                 tot += sum(o[2] for o in pick(r))
                 n += 1
         return 1e-6 * tot / n if n and tot else None
+
+    def collective_ms(self, exposed: bool = False) -> float | None:
+        """Device time per round of the collective ops in the round
+        program's executions, on the chip read; with ``exposed``, only the
+        time in which no other leaf op runs. A collective counts whether
+        or not other ops run inside it (an async one's ``-done`` half can
+        span them). None where no round has one."""
+        tot, n, found = 0, 0, False
+        for dev in self.devices:
+            ops, lv = self.ops(dev), self.leaf_ops(dev)
+            for a, b in self.main_module(dev):
+                comm = [o[1:3] for o in ops
+                        if a <= o[1] < b and is_collective(o[0])]
+                rest = [o[1:3] for o in lv
+                        if a <= o[1] < b and not is_collective(o[0])]
+                found = found or bool(comm)
+                tot += (union_ns(comm + rest) - union_ns(rest) if exposed
+                        else union_ns(comm))
+                n += 1
+        return 1e-6 * tot / n if found else None
 
     def top_ops(self, k: int = 10) -> list:
         agg: dict = {}
